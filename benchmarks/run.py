@@ -267,6 +267,8 @@ def main() -> None:
                     help="rewrite the baseline from this run (restricted "
                          "to the BASELINE_KEYS round-domain allowlist)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     modules = SMOKE_MODULES if args.smoke else MODULES
     mode = "smoke" if args.smoke else "full"
     out = args.bench_out

@@ -74,7 +74,7 @@ def draft_specs(dcfg: ModelConfig) -> dict:
 
 
 def draft_init(dcfg: ModelConfig, key):
-    return init_params(key, draft_specs(dcfg))
+    return init_params(key, draft_specs(dcfg), dcfg.weight_dtype)
 
 
 def draft_param_count(dcfg: ModelConfig) -> int:

@@ -284,7 +284,11 @@ class TideSystem:
                 capacity=max(tide_cfg.channel_capacity,
                              tide_cfg.n_threshold),
                 device=train_device)
-            self.trainer = DraftTrainer(cfg, self.dcfg, params["embed"])
+            # the trainer reads only the frozen embeddings: keep a copy
+            # on its own device, next to the drafts it trains
+            embed = (params["embed"] if train_device is None
+                     else jax.device_put(params["embed"], train_device))
+            self.trainer = DraftTrainer(cfg, self.dcfg, embed)
             self.gate = DraftDeployGate(dparams)
             self.service = TrainingService(
                 self.trainer, self.gate, self.channel,

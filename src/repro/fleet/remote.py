@@ -197,10 +197,21 @@ class RemoteTrainingService:
 
     # ---------------------------------------------------------- transport
     def _spawn(self) -> str:
+        import jax
+        backend = jax.default_backend()
+        if backend == "tpu":
+            # this process holds every local chip, so a child could
+            # neither open one nor should it train on the host CPU
+            raise RuntimeError(
+                "trainer_endpoint='spawn' cannot place a trainer on a TPU "
+                "host: the serving process holds the chips, and "
+                "per-process chip assignment (ROADMAP Speed 5) is not "
+                "implemented; train in process (no trainer_endpoint) or "
+                "connect to a trainer running elsewhere")
         self._tmpdir = tempfile.mkdtemp(prefix="tide-fleet-")
         endpoint = f"unix:{os.path.join(self._tmpdir, 'trainer.sock')}"
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = backend     # the child trains where we serve
         import repro
         # namespace package: no __file__, locate via __path__
         src = os.path.dirname(os.path.abspath(list(repro.__path__)[0]))

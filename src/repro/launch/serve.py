@@ -240,12 +240,14 @@ def main():
 
     import repro.configs as configs
     from repro.core.adaptive import analytic_tpu_profile
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.core.tide import TideConfig, TideSystem
     from repro.data.workloads import (Phase, WorkloadStream, arrival_trace,
                                       make_domains, training_corpus)
     from repro.models import transformer as T
     from repro.training.trainer import pretrain_target
 
+    enable_compile_cache()
     cfg = configs.get(args.arch) if args.arch == "tide-tiny" \
         else configs.get_reduced(args.arch)
     if cfg.family in ("audio", "vlm"):
@@ -330,6 +332,7 @@ def main():
               f"{args.trace_out}")
     if args.flight_record:
         _print_flight_digest(sys_.recorder)
+    _exit_on_trainer_failures(s, sys_.service)
 
 
 def _main_fleet(args, cfg, params, tc, profile, domains):
@@ -362,6 +365,16 @@ def _main_fleet(args, cfg, params, tc, profile, domains):
           f"{s['replicas']} replicas) ==")
     for k, v in s.items():
         print(f"  {k}: {v:.3f}" if isinstance(v, float) else f"  {k}: {v}")
+    _exit_on_trainer_failures(s, fleet.service)
+
+
+def _exit_on_trainer_failures(summary, service):
+    """The training service keeps serving through a failed train
+    cycle; the launcher does not pass such a run as a success."""
+    n = summary["trainer_failures"]
+    if n:
+        print(f"  last trainer error: {service.last_error}")
+        raise SystemExit(f"draft training failed {n} time(s)")
 
 
 def _start_metrics_printer(sys_, interval: float):

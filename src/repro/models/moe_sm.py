@@ -36,18 +36,6 @@ from repro.models.layers import ffn
 from repro.models.config import FFN_SWIGLU
 
 
-def _shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``shard_map`` moved (experimental → jax.*) and renamed its
-    replication-check kwarg (check_rep → check_vma) across JAX versions;
-    resolve whichever this JAX provides."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
-
-
 def _local_dispatch(cfg: ModelConfig, params, xf):
     """The local-shard part of moe_sort. xf: (n_loc, D)."""
     n, d = xf.shape
@@ -133,7 +121,7 @@ def moe_shard_map(cfg: ModelConfig, params, x, mesh, *,
     shard_params = {k: params[k] for k in wspecs}
     from repro.models import hints
     with hints.suspend():     # mesh axes are manual inside shard_map
-        out, aux = _shard_map_compat(
+        out, aux = jax.shard_map(
             local, mesh=mesh,
             in_specs=(in_x, wspecs),
             out_specs=(in_x, P()),

@@ -64,11 +64,15 @@ def init_leaf(key, spec: ParamSpec):
             ).astype(spec.dtype)
 
 
-def init_params(key, specs):
-    """Materialize a pytree of ParamSpec into a pytree of arrays."""
+def init_params(key, specs, dtype=None):
+    """Materialize a pytree of ParamSpec into a pytree of arrays, cast to
+    ``dtype`` (a config's ``param_dtype``) leaf by leaf as each is drawn,
+    so a bf16 model never holds its whole float32 draw at once."""
     leaves, treedef = jax.tree.flatten(specs, is_leaf=is_spec)
     keys = jax.random.split(key, len(leaves))
-    vals = [init_leaf(k, s) for k, s in zip(keys, leaves)]
+    vals = [init_leaf(k, s) if dtype is None
+            else init_leaf(k, s).astype(dtype)
+            for k, s in zip(keys, leaves)]
     return jax.tree.unflatten(treedef, vals)
 
 

@@ -103,7 +103,7 @@ def param_specs(cfg: ModelConfig) -> dict:
 
 
 def init(cfg: ModelConfig, key):
-    return init_params(key, param_specs(cfg))
+    return init_params(key, param_specs(cfg), cfg.weight_dtype)
 
 
 # ============================================================== layer apply

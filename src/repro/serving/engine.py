@@ -996,21 +996,24 @@ class ServingEngine:
 
         self._reseed_fn = None
         if self.reseed_window and self.superstep_rounds > 0:
+            # the embeddings go in as an argument: a closed-over array
+            # would be baked into the program as a constant
             if self.paged:
-                @functools.partial(jax.jit, donate_argnums=(1,))
-                def _reseed(dparams, dcache, state):
+                @functools.partial(jax.jit, donate_argnums=(2,))
+                def _reseed(embed, dparams, dcache, state):
                     return eagle.reseed_draft_rows_from_ring_paged(
-                        dcfg, dparams, self.params["embed"], dcache,
+                        dcfg, dparams, embed, dcache,
                         state.cap_feats, state.cap_toks, state.cap_count,
                         self.max_len)
             else:
-                @functools.partial(jax.jit, donate_argnums=(1,))
-                def _reseed(dparams, dcache, state):
+                @functools.partial(jax.jit, donate_argnums=(2,))
+                def _reseed(embed, dparams, dcache, state):
                     return eagle.reseed_draft_rows_from_ring(
-                        dcfg, dparams, self.params["embed"], dcache,
+                        dcfg, dparams, embed, dcache,
                         state.cap_feats, state.cap_toks, state.cap_count)
 
-            self._reseed_fn = _reseed
+            self._reseed_fn = (lambda dparams, dcache, state: _reseed(
+                self.params["embed"], dparams, dcache, state))
 
         # ---- preemption spill/restore ops (superstep mode).  Spill
         # gathers one lane's full serving state — target-cache rows,
@@ -1160,8 +1163,8 @@ class ServingEngine:
         ``*_fn`` attribute (and the shared null-key constant) with the
         donor's, so XLA traces/compiles once per fleet.  Safe only when
         every trace-time capture matches: same model/draft configs, the
-        same params object (``_reseed_fn`` bakes ``params['embed']``
-        in), and an equal ServingConfig minus the per-replica
+        same params object (``_reseed_fn`` reads ``params['embed']``),
+        and an equal ServingConfig minus the per-replica
         ``completion_sink`` (equal seed ⇒ equal baked-in base sampling
         key, so sampled streams stay request-keyed and replica-
         invariant)."""

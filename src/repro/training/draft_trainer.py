@@ -8,6 +8,7 @@ the same logical-axis rules when run under a mesh; on CPU it runs as-is.
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -35,16 +36,23 @@ class DraftTrainer:
         self.ttt = ttt
         self.log: List[Dict] = []
 
-        def loss_fn(dparams, feats, tokens):
+        # the frozen embeddings are an argument, not a closure: jit bakes
+        # a closed-over array into the program as a constant (the whole
+        # vocab x d_model table, at real widths), and a constant cannot
+        # follow the trainer onto its own device
+        def loss_fn(dparams, embed_params, feats, tokens):
             return eagle.draft_train_loss(
-                self.dcfg, dparams, self.embed_params, feats, tokens,
+                self.dcfg, dparams, embed_params, feats, tokens,
                 ttt=self.ttt)
 
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
-        @jax.jit
-        def step(dparams, opt_state, feats, tokens, it):
-            (loss, metrics), grads = grad_fn(dparams, feats, tokens)
+        # the optimizer state is private to one cycle: donating it lets
+        # the update reuse the moment buffers in place
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def step(dparams, opt_state, embed_params, feats, tokens, it):
+            (loss, metrics), grads = grad_fn(dparams, embed_params, feats,
+                                             tokens)
             dparams, opt_state = self.opt.update(dparams, grads, opt_state,
                                                  it)
             return dparams, opt_state, loss, metrics["accuracy"]
@@ -52,9 +60,9 @@ class DraftTrainer:
         self._step = step
 
         @jax.jit
-        def eval_acc(dparams, feats, tokens):
+        def eval_acc(dparams, embed_params, feats, tokens):
             _, metrics = eagle.draft_train_loss(
-                self.dcfg, dparams, self.embed_params, feats, tokens,
+                self.dcfg, dparams, embed_params, feats, tokens,
                 ttt=False)
             return metrics["accuracy"]
 
@@ -98,14 +106,15 @@ class DraftTrainer:
             for s0 in range(0, len(order) - bs + 1, bs):
                 sel = order[s0:s0 + bs]
                 dparams, opt_state, loss, acc = self._step(
-                    dparams, opt_state, jnp.asarray(tf[sel]),
-                    jnp.asarray(tt[sel]), jnp.int32(it))
+                    dparams, opt_state, self.embed_params,
+                    jnp.asarray(tf[sel]), jnp.asarray(tt[sel]),
+                    jnp.int32(it))
                 last_acc = float(acc)
                 self.log.append({"it": it, "loss": float(loss),
                                  "acc": last_acc})
                 it += 1
-        eval_acc = (float(self._eval(dparams, jnp.asarray(ef),
-                                     jnp.asarray(et)))
+        eval_acc = (float(self._eval(dparams, self.embed_params,
+                                     jnp.asarray(ef), jnp.asarray(et)))
                     if ef.shape[0] else last_acc)
         return {"dparams": dparams, "train_acc": last_acc,
                 "eval_acc": eval_acc, "steps": it,

@@ -168,7 +168,10 @@ class TrainingService:
             dparams, _ = self.gate.current()
             ctx = contextlib.nullcontext()
             if self.device is not None:
+                # the gate's draft may still be the serving device's
+                # copy; one program cannot take arguments on two devices
                 import jax
+                dparams = jax.device_put(dparams, self.device)
                 ctx = jax.default_device(self.device)
             with ctx, self.tracer.span("train.cycle",
                                        batches=len(batches)):

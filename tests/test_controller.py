@@ -2,10 +2,7 @@
 property tests on the system's control invariants."""
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:   # not in the container image - deterministic shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.adaptive import (AdaptiveDrafter,
                                  alpha_from_accept_len,

@@ -42,6 +42,38 @@ def test_fleet_config_validation():
         FleetConfig(route="random")
 
 
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_spawn_trains_on_the_parents_platform(monkeypatch, backend):
+    """A spawned trainer inherits the serving process's platform; on a
+    TPU host, where this process holds the chips, spawn refuses loudly
+    instead of training on the host CPU."""
+    import subprocess
+
+    import jax
+    spawned = []
+
+    class _Exited:
+        returncode = 1
+
+        def __init__(self, cmd, env, **kw):
+            spawned.append(env)
+
+        def poll(self):
+            return self.returncode
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(subprocess, "Popen", _Exited)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(RuntimeError) as err:
+        RemoteTrainingService("spawn", tcfg=None, dcfg=None,
+                              embed_params={}, dparams0={})
+    if backend == "tpu":
+        assert "Speed 5" in str(err.value) and not spawned
+    else:
+        assert "exited with code 1" in str(err.value)
+        assert [env["JAX_PLATFORMS"] for env in spawned] == ["cpu"]
+
+
 def test_router_least_loaded_balances():
     r = FleetRouter(2, "least")
     big = Request(prompt=[1] * 8, max_new_tokens=100)

@@ -25,10 +25,7 @@ import jax
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:   # not in the container image - deterministic shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.configs as C
 from repro.core import eagle, paging
@@ -287,7 +284,7 @@ def test_preempted_victim_finishing_in_flight_is_dropped():
 
 
 @pytest.mark.slow
-@settings(max_examples=4)
+@settings(max_examples=4, deadline=None)
 @given(st.integers(6, 14), st.integers(40, 70), st.integers(0, 10 ** 6))
 def test_preempt_parity_property(burst_round, loose_budget, seed):
     """Randomized overload traces (random burst timing, loose budgets,

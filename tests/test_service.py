@@ -528,3 +528,16 @@ def test_completion_sink_bounds_scheduler(pretrained):
     assert out == [], "sink mode must not retain completions"
     assert sorted(r.rid for r in sunk) == sorted(r.rid for r in reqs)
     assert all(r.finish_t is not None for r in sunk)
+
+
+def test_serve_launcher_fails_a_run_with_trainer_failures(capsys):
+    """Serving survives a failed train cycle; the launcher's exit code
+    does not hide it."""
+    import types
+
+    from repro.launch.serve import _exit_on_trainer_failures
+    svc = types.SimpleNamespace(last_error="RuntimeError: boom")
+    _exit_on_trainer_failures({"trainer_failures": 0}, svc)
+    with pytest.raises(SystemExit, match="2 time"):
+        _exit_on_trainer_failures({"trainer_failures": 2}, svc)
+    assert "RuntimeError: boom" in capsys.readouterr().out

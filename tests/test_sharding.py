@@ -2,10 +2,7 @@
 cache/param tree alignment (hypothesis property tests)."""
 import jax
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:   # not in the container image - deterministic shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.launch import sharding as sh
@@ -14,10 +11,7 @@ from repro.launch.mesh import make_demo_mesh
 
 def _amesh(sizes, names):
     # fake abstract mesh: axis *names* drive the rule logic
-    try:
-        return jax.sharding.AbstractMesh(sizes, names)
-    except TypeError:   # jax<=0.4.x signature: ((name, size), ...)
-        return jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
+    return jax.sharding.AbstractMesh(sizes, names)
 
 
 def _mesh_2d():

@@ -9,10 +9,7 @@ of the stream even though the height is approximate).
 """
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:   # not in the container image - deterministic shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.serving.stats import P2Quantile, Peak, Ring
 

@@ -26,10 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:   # not in the container image - deterministic shim
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.configs as C
 from repro.core import eagle, speculative as spec
@@ -354,7 +351,7 @@ def _parity_case(lens, budgets, seed, *, greedy=True, rounds=4,
 
 
 @pytest.mark.slow
-@settings(max_examples=5)
+@settings(max_examples=5, deadline=None)
 @given(st.integers(0, 1), st.integers(0, 1), st.integers(0, 10 ** 6))
 def test_tree_width1_stream_parity_property(greedy_idx, paged_idx, seed):
     """Property: for random prompt lengths, budgets, and decode modes,

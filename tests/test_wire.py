@@ -11,10 +11,7 @@ import zlib
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                     # pragma: no cover
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.signals import SignalBatch
 from repro.fleet import wire

@@ -1,0 +1,9 @@
+"""Device: share of the traced window in which no operation ran on the
+serving chip, 1 - busy / window, in percent."""
+
+
+def read(ctx):
+    t = ctx["trace"]
+    if not t or t["window_s"] <= 0 or t["busy_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

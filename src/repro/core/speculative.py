@@ -359,54 +359,57 @@ def spec_decode_step(cfg: ModelConfig, dcfg: ModelConfig, tparams, dparams,
             key = jax.random.key(0)
         k_draft, k_ver = jax.random.split(key)
 
-    # 1) draft catches up on everything committed last round
-    ext_logits, ext_h, dcache = eagle.draft_extend(
-        dcfg, dparams, tparams["embed"], dcache,
-        carry.feats, carry.tokens, carry.advance)
-    last = (carry.advance - 1)[:, None, None]
-    h_last = jnp.take_along_axis(ext_h, last, axis=1)[:, 0]
-    first_logits = jnp.take_along_axis(ext_logits, last, axis=1)[:, 0]
+    with jax.named_scope("draft_propose"):
+        # 1) draft catches up on everything committed last round
+        ext_logits, ext_h, dcache = eagle.draft_extend(
+            dcfg, dparams, tparams["embed"], dcache,
+            carry.feats, carry.tokens, carry.advance)
+        last = (carry.advance - 1)[:, None, None]
+        h_last = jnp.take_along_axis(ext_h, last, axis=1)[:, 0]
+        first_logits = jnp.take_along_axis(ext_logits, last, axis=1)[:, 0]
 
-    # 2) chain-draft γ tokens
-    draft_tokens, draft_logits, dcache = eagle.draft_propose(
-        dcfg, dparams, tparams["embed"], dcache, h_last, first_logits,
-        gamma, greedy=greedy,
-        key=None if keys is not None else k_draft,
-        keys=k_draft if keys is not None else None)
+        # 2) chain-draft γ tokens
+        draft_tokens, draft_logits, dcache = eagle.draft_propose(
+            dcfg, dparams, tparams["embed"], dcache, h_last, first_logits,
+            gamma, greedy=greedy,
+            key=None if keys is not None else k_draft,
+            keys=k_draft if keys is not None else None)
 
-    # 3) target verify: t0 = last committed token (pair index advance-1)
-    t0 = jnp.take_along_axis(carry.tokens, (carry.advance - 1)[:, None],
-                             axis=1)
-    block = jnp.concatenate([t0, draft_tokens], axis=1)
-    out = T.decode_step(cfg, tparams, cache, block, moe_impl=moe_impl)
-    tl = out["logits"]                                     # (B, γ+1, V)
+    with jax.named_scope("target_verify"):
+        # 3) target verify: t0 = last committed token (pair index advance-1)
+        t0 = jnp.take_along_axis(carry.tokens, (carry.advance - 1)[:, None],
+                                 axis=1)
+        block = jnp.concatenate([t0, draft_tokens], axis=1)
+        out = T.decode_step(cfg, tparams, cache, block, moe_impl=moe_impl)
+        tl = out["logits"]                                     # (B, γ+1, V)
 
-    # 4) acceptance
-    if greedy:
-        n_acc, bonus = verify_greedy(tl, draft_tokens)
-    elif keys is not None:
-        n_acc, bonus = verify_sample(None, tl, draft_logits, draft_tokens,
-                                     keys=k_ver)
-    else:
-        n_acc, bonus = verify_sample(k_ver, tl, draft_logits, draft_tokens)
-    n_commit = n_acc + 1
+        # 4) acceptance
+        if greedy:
+            n_acc, bonus = verify_greedy(tl, draft_tokens)
+        elif keys is not None:
+            n_acc, bonus = verify_sample(None, tl, draft_logits, draft_tokens,
+                                         keys=k_ver)
+        else:
+            n_acc, bonus = verify_sample(k_ver, tl, draft_logits, draft_tokens)
+        n_commit = n_acc + 1
 
-    # commit target cache (per-request rollback for SSM states)
-    cache = T.commit_cache(cfg, out["cache"], n_commit)
-    # draft cache: roll the speculative lengths back (stale entries get
-    # overwritten by next round's extend)
-    dcache = eagle.reset_propose(dcache, gamma)
+    with jax.named_scope("commit"):
+        # commit target cache (per-request rollback for SSM states)
+        cache = T.commit_cache(cfg, out["cache"], n_commit)
+        # draft cache: roll the speculative lengths back (stale entries get
+        # overwritten by next round's extend)
+        dcache = eagle.reset_propose(dcache, gamma)
 
-    # committed tokens this round: [d1..d_{n_acc}, bonus, scratch...]
-    idx = jnp.arange(gamma + 1)[None, :]
-    accept_mask = idx < n_commit[:, None]
-    committed = jnp.where(idx < n_acc[:, None],
-                          jnp.pad(draft_tokens, ((0, 0), (0, 1))),
-                          bonus[:, None])
-    committed = jnp.where(accept_mask, committed, 0)
-    # next round's pending pairs: (caps[j], committed[j]) for j < n_commit
-    caps = out["captures"]                                  # (B, γ+1, 3D)
-    carry = SpecCarry(caps, committed, n_commit)
+        # committed tokens this round: [d1..d_{n_acc}, bonus, scratch...]
+        idx = jnp.arange(gamma + 1)[None, :]
+        accept_mask = idx < n_commit[:, None]
+        committed = jnp.where(idx < n_acc[:, None],
+                              jnp.pad(draft_tokens, ((0, 0), (0, 1))),
+                              bonus[:, None])
+        committed = jnp.where(accept_mask, committed, 0)
+        # next round's pending pairs: (caps[j], committed[j]) for j < n_commit
+        caps = out["captures"]                                  # (B, γ+1, 3D)
+        carry = SpecCarry(caps, committed, n_commit)
 
     return {"tokens": committed, "n_commit": n_commit, "cache": cache,
             "dcache": dcache, "carry": carry, "captures": caps,
@@ -444,59 +447,62 @@ def tree_decode_step(cfg: ModelConfig, dcfg: ModelConfig, tparams, dparams,
             key = jax.random.key(0)
         k_draft, k_ver = jax.random.split(key)
 
-    # 1) draft catches up on everything committed last round
-    ext_logits, ext_h, dcache = eagle.draft_extend(
-        dcfg, dparams, tparams["embed"], dcache,
-        carry.feats, carry.tokens, carry.advance)
-    last = (carry.advance - 1)[:, None, None]
-    h_last = jnp.take_along_axis(ext_h, last, axis=1)[:, 0]
-    first_logits = jnp.take_along_axis(ext_logits, last, axis=1)[:, 0]
+    with jax.named_scope("draft_propose"):
+        # 1) draft catches up on everything committed last round
+        ext_logits, ext_h, dcache = eagle.draft_extend(
+            dcfg, dparams, tparams["embed"], dcache,
+            carry.feats, carry.tokens, carry.advance)
+        last = (carry.advance - 1)[:, None, None]
+        h_last = jnp.take_along_axis(ext_h, last, axis=1)[:, 0]
+        first_logits = jnp.take_along_axis(ext_logits, last, axis=1)[:, 0]
 
-    # 2) draft the token tree (branch 0 == the chain proposal)
-    toks_tree, logits_tree, dcache = eagle.draft_propose_tree(
-        dcfg, dparams, tparams["embed"], dcache, h_last, first_logits,
-        gamma, width, greedy=greedy,
-        key=None if keys is not None else k_draft,
-        keys=k_draft if keys is not None else None)
+        # 2) draft the token tree (branch 0 == the chain proposal)
+        toks_tree, logits_tree, dcache = eagle.draft_propose_tree(
+            dcfg, dparams, tparams["embed"], dcache, h_last, first_logits,
+            gamma, width, greedy=greedy,
+            key=None if keys is not None else k_draft,
+            keys=k_draft if keys is not None else None)
 
-    # 3) one tree-masked target pass over [t0, flat nodes]
-    t0 = jnp.take_along_axis(carry.tokens, (carry.advance - 1)[:, None],
-                             axis=1)
-    block = jnp.concatenate([t0, toks_tree.reshape(b, width * gamma)],
-                            axis=1)
-    out = T.decode_step(cfg, tparams, cache, block, moe_impl=moe_impl,
-                        tree=(width, gamma))
-    tl = out["logits"]                                     # (B, T, V)
+    with jax.named_scope("target_verify"):
+        # 3) one tree-masked target pass over [t0, flat nodes]
+        t0 = jnp.take_along_axis(carry.tokens, (carry.advance - 1)[:, None],
+                                 axis=1)
+        block = jnp.concatenate([t0, toks_tree.reshape(b, width * gamma)],
+                                axis=1)
+        out = T.decode_step(cfg, tparams, cache, block, moe_impl=moe_impl,
+                            tree=(width, gamma))
+        tl = out["logits"]                                     # (B, T, V)
 
-    # 4) tree acceptance: longest root path
-    if greedy:
-        n_acc, sel, bonus = verify_tree_greedy(tl, toks_tree)
-    elif keys is not None:
-        n_acc, sel, bonus = verify_tree_sample(None, tl, logits_tree,
-                                               toks_tree, keys=k_ver)
-    else:
-        n_acc, sel, bonus = verify_tree_sample(k_ver, tl, logits_tree,
-                                               toks_tree)
-    n_commit = n_acc + 1
+        # 4) tree acceptance: longest root path
+        if greedy:
+            n_acc, sel, bonus = verify_tree_greedy(tl, toks_tree)
+        elif keys is not None:
+            n_acc, sel, bonus = verify_tree_sample(None, tl, logits_tree,
+                                                   toks_tree, keys=k_ver)
+        else:
+            n_acc, sel, bonus = verify_tree_sample(k_ver, tl, logits_tree,
+                                                   toks_tree)
+        n_commit = n_acc + 1
 
-    # 5) compact the accepted path into chain slots, then commit
-    cache = T.commit_cache(cfg, compact_tree_cache(out["cache"], sel,
-                                                   gamma), n_commit)
-    dcache = eagle.reset_propose(dcache, gamma)
+    with jax.named_scope("commit"):
+        # 5) compact the accepted path into chain slots, then commit
+        cache = T.commit_cache(cfg, compact_tree_cache(out["cache"], sel,
+                                                       gamma), n_commit)
+        dcache = eagle.reset_propose(dcache, gamma)
 
-    # 6) committed tokens / carry from the accepted path only
-    path = tree_path_slots(sel, gamma)                     # (B, γ+1)
-    tok_sel = jnp.take_along_axis(toks_tree, sel[:, None, None],
-                                  axis=1)[:, 0]            # (B, γ)
-    idx = jnp.arange(gamma + 1)[None, :]
-    accept_mask = idx < n_commit[:, None]
-    committed = jnp.where(idx < n_acc[:, None],
-                          jnp.pad(tok_sel, ((0, 0), (0, 1))),
-                          bonus[:, None])
-    committed = jnp.where(accept_mask, committed, 0)
-    caps = jnp.take_along_axis(out["captures"], path[..., None], axis=1)
-    tl_path = jnp.take_along_axis(tl, path[..., None], axis=1)
-    carry = SpecCarry(caps, committed, n_commit)
+        # 6) committed tokens / carry from the accepted path only
+        path = tree_path_slots(sel, gamma)                     # (B, γ+1)
+        tok_sel = jnp.take_along_axis(toks_tree, sel[:, None, None],
+                                      axis=1)[:, 0]            # (B, γ)
+        idx = jnp.arange(gamma + 1)[None, :]
+        accept_mask = idx < n_commit[:, None]
+        committed = jnp.where(idx < n_acc[:, None],
+                              jnp.pad(tok_sel, ((0, 0), (0, 1))),
+                              bonus[:, None])
+        committed = jnp.where(accept_mask, committed, 0)
+        caps = jnp.take_along_axis(out["captures"], path[..., None], axis=1)
+        tl_path = jnp.take_along_axis(tl, path[..., None], axis=1)
+        carry = SpecCarry(caps, committed, n_commit)
 
     return {"tokens": committed, "n_commit": n_commit, "cache": cache,
             "dcache": dcache, "carry": carry, "captures": caps,
@@ -884,18 +890,20 @@ def decode_superstep(cfg: ModelConfig, dcfg: ModelConfig, tparams, dparams,
                             + (1.0 - ema_decay) * ell,
                             st.accept_ema)
 
-            remaining = jnp.maximum(max_new - st.gen_count, 0)
-            n_eff = jnp.where(act, jnp.minimum(n_commit, remaining), 0)
-            if eos_id is not None:
-                pos = jnp.arange(gp1)[None, :]
-                is_eos = (tokens == eos_id) & (pos < n_eff[:, None])
-                has_eos = is_eos.any(axis=1)
-                n_eff = jnp.where(has_eos, is_eos.argmax(axis=1) + 1, n_eff)
-            else:
-                has_eos = jnp.zeros_like(act)
-            gen_new = st.gen_count + n_eff
-            active_after = act & (gen_new < max_new) & ~has_eos
-            n_sig = jnp.where(active_after, n_commit, 0).sum()
+            with jax.named_scope("commit"):
+                remaining = jnp.maximum(max_new - st.gen_count, 0)
+                n_eff = jnp.where(act, jnp.minimum(n_commit, remaining), 0)
+                if eos_id is not None:
+                    pos = jnp.arange(gp1)[None, :]
+                    is_eos = (tokens == eos_id) & (pos < n_eff[:, None])
+                    has_eos = is_eos.any(axis=1)
+                    n_eff = jnp.where(has_eos, is_eos.argmax(axis=1) + 1,
+                                      n_eff)
+                else:
+                    has_eos = jnp.zeros_like(act)
+                gen_new = st.gen_count + n_eff
+                active_after = act & (gen_new < max_new) & ~has_eos
+                n_sig = jnp.where(active_after, n_commit, 0).sum()
 
             ys = {"tokens": tokens, "n_commit": n_commit, "n_eff": n_eff,
                   "active_after": active_after, "use_spec": use_spec,
@@ -904,8 +912,9 @@ def decode_superstep(cfg: ModelConfig, dcfg: ModelConfig, tparams, dparams,
             if collect_signals:
                 # only tokens actually kept (post EOS/budget cut) become
                 # training signals — never continuations past the end
-                sig_mask = jnp.arange(gp1)[None, :] < n_eff[:, None]
-                pf, pt, cnt = pack_signals(captures, tokens, sig_mask)
+                with jax.named_scope("signal_pack"):
+                    sig_mask = jnp.arange(gp1)[None, :] < n_eff[:, None]
+                    pf, pt, cnt = pack_signals(captures, tokens, sig_mask)
                 ys["sig_feats"], ys["sig_tokens"], ys["sig_counts"] = \
                     pf, pt, cnt
             st = SuperstepState(carry, active_after, gen_new, ema,

@@ -130,8 +130,8 @@ class RemoteTrainingService:
         self.engine_steps_fn = engine_steps_fn or (lambda: -1)
         self.poll_s = poll_s
         self.drain_timeout = drain_timeout
-        from repro.obs.trace import NULL_TRACER
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        from repro.obs.trace import DEFAULT_TRACER
+        self.tracer = tracer if tracer is not None else DEFAULT_TRACER
 
         self.channel = RemoteSignalChannel(
             capacity=max(channel_capacity,

@@ -5,7 +5,8 @@ existing host telemetry boundaries** (superstep unpack, scheduler
 admission, trainer publish, deploy poll) and therefore add **zero
 device<->host synchronizations** to the serving path:
 
-- :mod:`repro.obs.trace` — a ring-buffered span/event tracer exporting
+- :mod:`repro.obs.trace` — a ring-buffered span/event tracer whose spans
+  also land in a running JAX profiler session's trace, exporting
   Chrome/Perfetto trace-event JSON (``chrome://tracing`` / ui.perfetto.dev).
 - :mod:`repro.obs.metrics` — a namespaced Counter/Gauge/Histogram
   registry (``serving.*``, ``train.*``, ``paging.*``, ``spec.*``) with
@@ -15,22 +16,22 @@ device<->host synchronizations** to the serving path:
   first token -> commits/parks/probes -> finish) from rounds the engine
   already unpacks.
 
-The disabled path is the default: ``NULL_TRACER`` / ``NULL_RECORDER``
-singletons answer ``.enabled == False`` so hot-loop guards are a single
-attribute check and the off configuration stays byte-identical to a
-build without this package.
+The disabled path is the default: ``DEFAULT_TRACER`` records only while a
+JAX profiler session runs and ``NULL_RECORDER`` never records, so with
+no session hot-loop guards are a single check and the off configuration
+stays byte-identical to a build without this package.
 """
 import dataclasses
 from typing import Optional
 
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry)
 from repro.obs.recorder import FlightRecorder, NullRecorder, NULL_RECORDER
-from repro.obs.trace import NullTracer, NULL_TRACER, Tracer
+from repro.obs.trace import DEFAULT_TRACER, Tracer
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "FlightRecorder", "NullRecorder", "NULL_RECORDER",
-    "NullTracer", "NULL_TRACER", "Tracer",
+    "DEFAULT_TRACER", "Tracer",
     "ObsConfig",
 ]
 
@@ -43,16 +44,18 @@ class ObsConfig:
     ``ServingConfig`` knob: it builds runtime instrument objects that
     are handed to the engine/trainer as collaborators.
     """
-    trace: bool = False                 # enable the span tracer
+    trace: bool = False                 # record spans always
     trace_capacity: int = 65536         # ring capacity (events)
     trace_path: Optional[str] = None    # export trace JSON here on close
     record: bool = False                # enable the flight recorder
     record_capacity: int = 1024         # finished-request timelines kept
 
     def build(self):
-        """Return ``(tracer, recorder)`` per the toggles (null when off)."""
+        """Return ``(tracer, recorder)`` per the toggles: off, the
+        tracer is ``DEFAULT_TRACER`` (records during a profiler session)
+        and the recorder the null one."""
         on = self.trace or self.trace_path is not None
-        tracer = Tracer(self.trace_capacity) if on else NULL_TRACER
+        tracer = Tracer(self.trace_capacity) if on else DEFAULT_TRACER
         rec = FlightRecorder(self.record_capacity) if self.record \
             else NULL_RECORDER
         return tracer, rec

@@ -183,17 +183,19 @@ The old batch-global key chain made sampled parity hold only on
 refill-free streams.
 
 Observability (``repro/obs``; docs/observability.md): the engine takes
-three optional host-side collaborators — a span ``tracer`` (superstep
-dispatch/unpack, prefill chunk/commit, refill, reseed, idle spans plus
-sched/deploy/spec instants), a per-request flight ``recorder``
+three optional host-side collaborators — a span ``tracer`` (spans that
+tile each superstep iteration: dispatch, the host's waits on telemetry,
+unpack, boundary, refill and prefill chunks, idle; plus token-delivery,
+sched, deploy and spec instants), a per-request flight ``recorder``
 (admit → prefill chunks → first token → per-round commits → finish),
 and a ``metrics`` registry that ``ServingStats`` registers its
 counters/histograms/derived gauges into under the ``serving.*``
 namespace (``spec.*`` and ``paging.*`` gauges ride along).  Every hook
 sits at a boundary the host already crosses — nothing new is pulled
-from the device, so observability-on serving adds **zero** syncs and
-defaults (``NULL_TRACER``/``NULL_RECORDER``) make the disabled path a
-single attribute check; obs-on streams are byte-identical to obs-off
+from the device, so observability-on serving adds **zero** syncs; the
+defaults (``DEFAULT_TRACER``, which records only while a JAX profiler
+session runs, and ``NULL_RECORDER``) make the disabled path a single
+check; obs-on streams are byte-identical to obs-off
 (tests/test_obs.py, gated with a ≤1.03x wall bound in
 benchmarks/bench_hotloop.py).
 
@@ -231,7 +233,7 @@ from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.recorder import NULL_RECORDER
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import DEFAULT_TRACER
 from repro.serving.policy import ServingConfig, ServingPolicy
 from repro.serving.request import Request, inert_request
 from repro.serving.scheduler import Scheduler
@@ -615,11 +617,12 @@ class ServingEngine:
         # mix real and fake time
         # ---------------------------------------------- observability
         # Host-side instruments only (docs/observability.md): the tracer
-        # and flight recorder default to null singletons whose hooks are
-        # attribute-check cheap, and every ServingStats counter lives in
+        # defaults to DEFAULT_TRACER (records only during a profiler
+        # session) and the recorder to the null one, both one check per
+        # hook otherwise, and every ServingStats counter lives in
         # the metrics registry (``serving.*``), shared with the training
         # service / allocator when the system layer passes one in.
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        self.tracer = tracer if tracer is not None else DEFAULT_TRACER
         self.recorder = recorder if recorder is not None else NULL_RECORDER
         self.metrics = metrics if metrics is not None else MetricsRegistry()
         self.stats = ServingStats(registry=self.metrics)
@@ -1343,6 +1346,18 @@ class ServingEngine:
         if self.eos_id is not None and tok == self.eos_id:
             self._finish(r)
 
+    def _commit_firsts(self, fdev, admitted):
+        """Pull a refill's first tokens to the host (the wait is the
+        ``refill.first_wait`` span) and commit them, row by row, to the
+        admitted ``(slot, request)`` pairs."""
+        with self.tracer.span("refill.first_wait"):
+            first_np = np.asarray(fdev)
+        for row, (_, req) in enumerate(admitted):
+            self._commit_first(req, int(first_np[row]))
+        if self.tracer.enabled:
+            self.tracer.instant("serve.deliver", rids=[
+                r.rid for _, r in admitted if r.first_token_t is not None])
+
     # ------------------------------------------------------------- prologue
     def _prologue(self, requests: List[Request]):
         """Pad + prefill + draft seed for one full batch of B slots.
@@ -1429,24 +1444,23 @@ class ServingEngine:
             if wait is None:
                 return sched.completed
             self._idle_tick(wait)       # gated stream not yet begun
-        admitted = sched.admit()
-        self._assign_sids(admitted)
-        reqs0 = [r if r is not None else inert_request()
-                 for r in sched.slots]
-        if self.prefill_chunk:
-            # chunked prefill: no one-shot prologue — the initial batch
-            # flows through the same chunk pipelines as every later
-            # refill, so no prompt ever stalls the engine for more than
-            # one chunk per gap
-            cache, dcache, carry, first = self._empty_state()
-            self._pipelines = []
-            self._spawn_pipelines(admitted)
-        else:
-            with self.tracer.span("prefill.prologue", rows=self.batch):
-                cache, dcache, carry, first = self._prologue(reqs0)
-            first_np = np.asarray(first)
-            for i, r in enumerate(reqs0):
-                self._commit_first(r, int(first_np[i]))
+        with self.tracer.span("serve.start"):
+            admitted = sched.admit()
+            self._assign_sids(admitted)
+            reqs0 = [r if r is not None else inert_request()
+                     for r in sched.slots]
+            if self.prefill_chunk:
+                # chunked prefill: no one-shot prologue — the initial
+                # batch flows through the same chunk pipelines as every
+                # later refill, so no prompt ever stalls the engine for
+                # more than one chunk per gap
+                cache, dcache, carry, first = self._empty_state()
+                self._pipelines = []
+                self._spawn_pipelines(admitted)
+            else:
+                with self.tracer.span("prefill.prologue", rows=self.batch):
+                    cache, dcache, carry, first = self._prologue(reqs0)
+                self._commit_firsts(first, list(enumerate(reqs0)))
         if self._superstep_fn is not None:
             self._stream_superstep(sched, reqs0, cache, dcache, carry,
                                    first, t0, on_complete,
@@ -1456,7 +1470,8 @@ class ServingEngine:
                                   on_complete,
                                   cold=bool(self.prefill_chunk))
         if self.extractor is not None:
-            self.extractor.flush()
+            with self.tracer.span("signals.flush"):
+                self.extractor.flush()
         self.stats.wall_s += self._clock() - t0
         return sched.completed
 
@@ -1992,9 +2007,7 @@ class ServingEngine:
             if pending is not None:
                 pending["refills"].append((fdev, pl.admitted))
             else:
-                first_np = np.asarray(fdev)
-                for row, (_, req) in enumerate(pl.admitted):
-                    self._commit_first(req, int(first_np[row]))
+                self._commit_firsts(fdev, pl.admitted)
 
         for pl in self._pipelines:
             if pl.ready:
@@ -2126,21 +2139,22 @@ class ServingEngine:
 
     def _stream_superstep(self, sched, reqs0, cache, dcache, carry, first,
                           t0, on_complete, cold=False):
-        if cold:
-            # chunked-prefill start: every lane is inert (budgets and
-            # activity land with its pipeline's commit)
-            max_new = jnp.zeros((self.batch,), jnp.int32)
-            active0 = jnp.zeros((self.batch,), bool)
-        else:
-            max_new = jnp.asarray([r.max_new_tokens for r in reqs0],
-                                  jnp.int32)
-            active0 = jnp.asarray([r.finish_t is None for r in reqs0],
-                                  bool)
-        state = spec.init_superstep_state(
-            carry, first, self._base_key, accept_ema=self.accept_ema,
-            eos_id=self.eos_id, active0=active0,
-            sids=self._slot_sids(reqs0),
-            capture_window=self.reseed_window)
+        with self.tracer.span("serve.start"):
+            if cold:
+                # chunked-prefill start: every lane is inert (budgets
+                # and activity land with its pipeline's commit)
+                max_new = jnp.zeros((self.batch,), jnp.int32)
+                active0 = jnp.zeros((self.batch,), bool)
+            else:
+                max_new = jnp.asarray([r.max_new_tokens for r in reqs0],
+                                      jnp.int32)
+                active0 = jnp.asarray([r.finish_t is None for r in reqs0],
+                                      bool)
+            state = spec.init_superstep_state(
+                carry, first, self._base_key, accept_ema=self.accept_ema,
+                eos_id=self.eos_id, active0=active0,
+                sids=self._slot_sids(reqs0),
+                capture_window=self.reseed_window)
         if cold and self._pipelines:
             # initial pipelines take the prologue's slot in the dispatch
             # order.  No lane is decoding yet, so there is nothing to
@@ -2149,11 +2163,12 @@ class ServingEngine:
             # gaps where residents decode)
             gap = 0
             commits = 0
-            while self._pipelines and commits == 0:
-                cache, dcache, state, max_new, g, commits = \
-                    self._advance_pipelines_ss(cache, dcache, state,
-                                               max_new, None)
-                gap += g
+            with self.tracer.span("prefill.advance"):
+                while self._pipelines and commits == 0:
+                    cache, dcache, state, max_new, g, commits = \
+                        self._advance_pipelines_ss(cache, dcache, state,
+                                                   max_new, None)
+                    gap += g
             if gap:
                 self.stats.prefill_gap_tokens.add(gap)
         # one-superstep double buffer: superstep t+1 is dispatched before
@@ -2174,9 +2189,9 @@ class ServingEngine:
                 self.stats.reseeds += 1
             dispatched = False
             if sched.has_work():
-                cache, dcache = self._ship_tables(cache, dcache)
                 with self.tracer.span("superstep.dispatch",
                                       rounds=self.superstep_rounds):
+                    cache, dcache = self._ship_tables(cache, dcache)
                     out = self._superstep_fn(
                         self.params, self.dparams, cache, dcache, state,
                         max_new, self.policy.speculation.dispatch_table())
@@ -2212,54 +2227,38 @@ class ServingEngine:
             with self.tracer.span("superstep.unpack"):
                 progressed = self._drain(prev, t0)
             n_restores0 = self.stats.restores
-            cache, dcache, state, max_new, admitted = \
-                self._overload_boundary(sched, on_complete, cache,
-                                        dcache, state, max_new)
+            with self.tracer.span("serve.boundary"):
+                cache, dcache, state, max_new, admitted = \
+                    self._overload_boundary(sched, on_complete, cache,
+                                            dcache, state, max_new)
             gap_tokens = 0
-            if admitted and self.prefill_chunk:
-                # chunked: new pipelines; their first chunks dispatch in
-                # the advance below, in the refill op's dispatch slot
-                self._spawn_pipelines(admitted)
-            elif admitted:
-                args = self._refill_arrays(admitted)
-                self._note_prefill_op(args[0].shape[0], args[0].shape[1])
-                gap_tokens += args[0].shape[0] * args[0].shape[1]
-                if self.paged:
-                    self._reserve_group(admitted, int(args[0].shape[1]))
-                    cache, dcache = self._ship_tables(cache, dcache)
-                with self.tracer.span("refill", rows=int(args[0].shape[0]),
-                                      width=int(args[0].shape[1])):
-                    cache, dcache, state, max_new, fdev = \
-                        self._refill_ss_fn(
-                            self.params, self.dparams, cache, dcache,
-                            state, max_new, *args)
-                self.stats.refills += len(admitted)
-                if self.paged:
-                    self._publish_prefixes(self._prefix_entries(
-                        admitted, int(args[0].shape[0]),
-                        int(args[0].shape[1])))
-                if pending is not None:
-                    # first tokens materialize with the next telemetry
-                    # pull — zero extra host syncs
-                    pending["refills"].append((fdev, admitted))
-                else:
-                    first_np = np.asarray(fdev)
-                    for row, (_, req) in enumerate(admitted):
-                        self._commit_first(req, int(first_np[row]))
-            if self._pipelines:
-                cache, dcache, state, max_new, gap, commits = \
-                    self._advance_pipelines_ss(cache, dcache, state,
-                                               max_new, pending)
+            if admitted and not self.prefill_chunk:
+                with self.tracer.span("refill", rows=len(admitted)):
+                    cache, dcache, state, max_new, gap = \
+                        self._refill_one_shot(admitted, cache, dcache,
+                                              state, max_new, pending)
                 gap_tokens += gap
-                # the drained superstep was empty (no resident lane
-                # decoding): nothing to interleave with, so run the
-                # pipelines straight to the next commit instead of
-                # trickling one idle chunk per empty dispatch
-                while self._pipelines and not progressed and commits == 0:
+            elif admitted or self._pipelines:
+                with self.tracer.span("prefill.advance"):
+                    if admitted:
+                        # chunked: new pipelines; their first chunks
+                        # dispatch in the advance below, in the refill
+                        # op's dispatch slot
+                        self._spawn_pipelines(admitted)
                     cache, dcache, state, max_new, gap, commits = \
                         self._advance_pipelines_ss(cache, dcache, state,
                                                    max_new, pending)
                     gap_tokens += gap
+                    # the drained superstep was empty (no resident lane
+                    # decoding): nothing to interleave with, so run the
+                    # pipelines straight to the next commit instead of
+                    # trickling one idle chunk per empty dispatch
+                    while (self._pipelines and not progressed
+                           and commits == 0):
+                        cache, dcache, state, max_new, gap, commits = \
+                            self._advance_pipelines_ss(
+                                cache, dcache, state, max_new, pending)
+                        gap_tokens += gap
             if gap_tokens:
                 self.stats.prefill_gap_tokens.add(gap_tokens)
             # defensive stall guard: every drained superstep must either
@@ -2273,20 +2272,45 @@ class ServingEngine:
                     "serve_stream made no progress over 5 supersteps "
                     "(device/host slot state diverged)")
 
+    def _refill_one_shot(self, admitted, cache, dcache, state, max_new,
+                         pending):
+        """One-shot refill of the admitted requests' lanes, enqueued
+        behind the in-flight superstep.  Their first tokens ride the
+        pending telemetry record (zero extra host syncs), or are
+        committed now when none is in flight.  Returns the updated
+        device bindings and the op's row-token cost."""
+        args = self._refill_arrays(admitted)
+        rows, width = int(args[0].shape[0]), int(args[0].shape[1])
+        self._note_prefill_op(rows, width)
+        if self.paged:
+            self._reserve_group(admitted, width)
+            cache, dcache = self._ship_tables(cache, dcache)
+        cache, dcache, state, max_new, fdev = self._refill_ss_fn(
+            self.params, self.dparams, cache, dcache, state, max_new,
+            *args)
+        self.stats.refills += len(admitted)
+        if self.paged:
+            self._publish_prefixes(self._prefix_entries(admitted, rows,
+                                                        width))
+        if pending is not None:
+            pending["refills"].append((fdev, admitted))
+        else:
+            self._commit_firsts(fdev, admitted)
+        return cache, dcache, state, max_new, rows * width
+
     def _drain(self, rec, t0) -> bool:
         """Unpack one in-flight superstep record: replay its telemetry,
         then commit the first tokens of any refill (one-shot or chunk-
         pipeline commit) that was enqueued behind it.  Returns True if
         any round was valid (progress)."""
-        ys = self._materialize(rec["rounds"])
+        with self.tracer.span("superstep.wait"):
+            ys = self._materialize(rec["rounds"])
         rids = [r.rid if r is not None else -1 for r in rec["slots"]]
         progressed = self._unpack_superstep(ys, rec["slots"], rids, t0,
                                             n_prefill=rec.get("n_prefill",
                                                               0))
         for fdev, admitted in rec["refills"]:
-            first_np = np.asarray(fdev)
-            for row, (_, req) in enumerate(admitted):
-                self._commit_first(req, int(first_np[row]))
+            self._commit_firsts(fdev, admitted)
         return progressed
 
     def _unpack_superstep(self, ys, requests, rids, t0,
@@ -2303,6 +2327,8 @@ class ServingEngine:
         sig_np = None            # lazily-fetched packed signal buffers
         any_valid = False
         rec_on = self.recorder.enabled
+        # requests that received tokens, for the delivery instant
+        got = set() if self.tracer.enabled else None
         for r in range(valid.shape[0]):
             if not valid[r]:
                 break
@@ -2319,6 +2345,8 @@ class ServingEngine:
                 n = int(n_eff[i])
                 if n:
                     req.generated.extend(int(t) for t in toks[i, :n])
+                    if got is not None:
+                        got.add(req.rid)
                     if rec_on:
                         self.recorder.note(req.rid, "commit",
                                            round_=self.stats.steps,
@@ -2357,9 +2385,10 @@ class ServingEngine:
             if (self.extractor is not None and self.extractor.enabled
                     and "sig_feats" in ys):
                 if sig_np is None:
-                    sig_np = tuple(np.asarray(ys[k]) for k in
-                                   ("sig_feats", "sig_tokens",
-                                    "sig_counts"))
+                    with self.tracer.span("signals.fetch"):
+                        sig_np = tuple(np.asarray(ys[k]) for k in
+                                       ("sig_feats", "sig_tokens",
+                                        "sig_counts"))
                 self.extractor.ingest_packed(
                     rids, sig_np[0][r], sig_np[1][r], sig_np[2][r])
             self.stats.timeline.append({
@@ -2367,6 +2396,8 @@ class ServingEngine:
                 "accept_len": ell, "alpha": alpha,
                 "decision": decision.value, "busy_lanes": busy,
             })
+        if got:
+            self.tracer.instant("serve.deliver", rids=sorted(got))
         return any_valid
 
     # ------------------------------------------ per-step reference loop

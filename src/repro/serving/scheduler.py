@@ -45,7 +45,7 @@ from collections import deque
 from typing import (Callable, Deque, Dict, Iterable, Iterator, List,
                     Optional, Tuple)
 
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import DEFAULT_TRACER
 from repro.serving.policy import AdmissionPolicy, FifoAdmission
 from repro.serving.request import Request
 
@@ -66,8 +66,9 @@ class Scheduler:
         self.batch = batch_size
         self.policy = policy if policy is not None else FifoAdmission()
         # host-side observability: admission instants + queue-depth
-        # counter samples (null by default — a no-op attribute check)
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        # counter samples (by default recorded only while a profiler
+        # session runs — otherwise one check)
+        self.tracer = tracer if tracer is not None else DEFAULT_TRACER
         # resource veto consulted per candidate during ``admit`` (paged
         # serving passes the page-pool guard): guard(candidate,
         # already-accepted-this-round) -> False defers the candidate.

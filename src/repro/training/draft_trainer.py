@@ -19,6 +19,7 @@ import numpy as np
 from repro.core import eagle
 from repro.core.signals import SignalBatch
 from repro.models.config import ModelConfig
+from repro.obs.trace import DEFAULT_TRACER
 from repro.training.optimizer import Optimizer, adamw
 
 
@@ -34,16 +35,16 @@ class DraftTrainer:
         self.opt = opt or adamw(lr=1e-3, weight_decay=0.0)
         self.batch_size = batch_size
         self.ttt = ttt
-        self.log: List[Dict] = []
 
         # the frozen embeddings are an argument, not a closure: jit bakes
         # a closed-over array into the program as a constant (the whole
         # vocab x d_model table, at real widths), and a constant cannot
         # follow the trainer onto its own device
         def loss_fn(dparams, embed_params, feats, tokens):
-            return eagle.draft_train_loss(
-                self.dcfg, dparams, embed_params, feats, tokens,
-                ttt=self.ttt)
+            with jax.named_scope("loss"):
+                return eagle.draft_train_loss(
+                    self.dcfg, dparams, embed_params, feats, tokens,
+                    ttt=self.ttt)
 
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
@@ -51,10 +52,12 @@ class DraftTrainer:
         # the update reuse the moment buffers in place
         @functools.partial(jax.jit, donate_argnums=(1,))
         def step(dparams, opt_state, embed_params, feats, tokens, it):
-            (loss, metrics), grads = grad_fn(dparams, embed_params, feats,
-                                             tokens)
-            dparams, opt_state = self.opt.update(dparams, grads, opt_state,
-                                                 it)
+            with jax.named_scope("gradient"):
+                (loss, metrics), grads = grad_fn(dparams, embed_params,
+                                                 feats, tokens)
+            with jax.named_scope("adamw_update"):
+                dparams, opt_state = self.opt.update(dparams, grads,
+                                                     opt_state, it)
             return dparams, opt_state, loss, metrics["accuracy"]
 
         self._step = step
@@ -87,35 +90,44 @@ class DraftTrainer:
     # --------------------------------------------------------------- cycle
     def train_cycle(self, dparams, batches: List[SignalBatch], *,
                     epochs: int = 2, min_steps: int = 80,
-                    seed: int = 0) -> Dict:
+                    seed: int = 0, tracer=DEFAULT_TRACER) -> Dict:
         """One training cycle on the drained signal buffer.  ``epochs`` is
         a floor — small buffers get extra epochs until ``min_steps``
         optimizer steps have run (training-until-saturation, paper Fig. 5).
+        ``tracer`` times its phases: ``train.stack`` (host arrays,
+        optimizer state and every epoch's sample order), then per step
+        ``train.feed`` (the batch built and copied to the device),
+        ``train.step`` (the dispatch) and ``train.read`` (the host's
+        wait for its accuracy), and ``train.eval``.
         Returns dict(dparams, train_acc, eval_acc, steps, seconds)."""
-        (tf, tt), (ef, et) = self.make_arrays(batches)
-        opt_state = self.opt.init(dparams)
-        rng = np.random.default_rng(seed)
-        bs = min(self.batch_size, max(tf.shape[0], 1))
-        steps_per_epoch = max(tf.shape[0] // bs, 1)
-        epochs = max(epochs, -(-min_steps // steps_per_epoch))
+        with tracer.span("train.stack"):
+            (tf, tt), (ef, et) = self.make_arrays(batches)
+            opt_state = self.opt.init(dparams)
+            bs = min(self.batch_size, max(tf.shape[0], 1))
+            steps_per_epoch = max(tf.shape[0] // bs, 1)
+            epochs = max(epochs, -(-min_steps // steps_per_epoch))
+            rng = np.random.default_rng(seed)
+            orders = [rng.permutation(tf.shape[0]) for _ in range(epochs)]
         t0 = time.perf_counter()
         it = 0
         last_acc = 0.0
-        for _ in range(epochs):
-            order = rng.permutation(tf.shape[0])
+        for order in orders:
             for s0 in range(0, len(order) - bs + 1, bs):
-                sel = order[s0:s0 + bs]
-                dparams, opt_state, loss, acc = self._step(
-                    dparams, opt_state, self.embed_params,
-                    jnp.asarray(tf[sel]), jnp.asarray(tt[sel]),
-                    jnp.int32(it))
-                last_acc = float(acc)
-                self.log.append({"it": it, "loss": float(loss),
-                                 "acc": last_acc})
+                with tracer.span("train.feed"):
+                    sel = order[s0:s0 + bs]
+                    feats, toks = jnp.asarray(tf[sel]), jnp.asarray(tt[sel])
+                    step_no = jnp.int32(it)
+                with tracer.span("train.step"):
+                    dparams, opt_state, _, acc = self._step(
+                        dparams, opt_state, self.embed_params, feats, toks,
+                        step_no)
+                with tracer.span("train.read"):
+                    last_acc = float(acc)
                 it += 1
-        eval_acc = (float(self._eval(dparams, self.embed_params,
-                                     jnp.asarray(ef), jnp.asarray(et)))
-                    if ef.shape[0] else last_acc)
+        with tracer.span("train.eval"):
+            eval_acc = (float(self._eval(dparams, self.embed_params,
+                                         jnp.asarray(ef), jnp.asarray(et)))
+                        if ef.shape[0] else last_acc)
         return {"dparams": dparams, "train_acc": last_acc,
                 "eval_acc": eval_acc, "steps": it,
                 "seconds": time.perf_counter() - t0}

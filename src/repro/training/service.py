@@ -71,8 +71,8 @@ class TrainingService:
         # observability (host-side, thread-safe): train-cycle spans +
         # deploy instants on the shared tracer, ``train.*`` gauges on
         # the shared metrics registry.  Both optional and null-cheap.
-        from repro.obs.trace import NULL_TRACER
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        from repro.obs.trace import DEFAULT_TRACER
+        self.tracer = tracer if tracer is not None else DEFAULT_TRACER
         if registry is not None:
             self.register_metrics(registry)
         self.controller = controller
@@ -173,11 +173,14 @@ class TrainingService:
                 import jax
                 dparams = jax.device_put(dparams, self.device)
                 ctx = jax.default_device(self.device)
-            with ctx, self.tracer.span("train.cycle",
-                                       batches=len(batches)):
+            # a cycle under way when a profiler session starts is kept
+            # whole: readers clip it to their window
+            with ctx, self.tracer.long_span("train.cycle",
+                                            batches=len(batches)):
                 result = self.trainer.train_cycle(
                     dparams, batches, epochs=self.train_epochs,
-                    min_steps=self.train_min_steps, seed=self.seed)
+                    min_steps=self.train_min_steps, seed=self.seed,
+                    tracer=self.tracer)
             deployed = self.gate.offer(result["dparams"],
                                        result["eval_acc"], baseline)
             if self.selective and self.controller is not None:

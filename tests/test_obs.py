@@ -4,8 +4,11 @@ flight recorder, and their engine/system integration.
 The load-bearing contract is **inertness**: observability-on serving
 must emit byte-identical token streams and the exact same device
 dispatch count as observability-off (hooks are host-side, at existing
-telemetry boundaries), and the null singletons must make the disabled
-path one attribute check.  On top of that: exported Chrome trace JSON
+telemetry boundaries), and the default tracer with no profiler session
+and the null recorder must make the disabled path one check.  Under a
+JAX profiler session the default tracer records the spans that tile
+the serving loop and the sync train cycle, in its ring and in the
+profile's host plane.  On top of that: exported Chrome trace JSON
 must be loadable and well-nested, ``metrics.snapshot()`` must agree
 with the legacy ``ServingStats`` / ``TideSystem.summary()`` counters,
 and the flight recorder must tell each request's whole story
@@ -20,6 +23,8 @@ import json
 import threading
 import time
 
+from bench import devtrace
+
 import jax
 import numpy as np
 import pytest
@@ -31,7 +36,8 @@ from repro.models import transformer as T
 from repro.obs import ObsConfig
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.recorder import NULL_RECORDER, FlightRecorder
-from repro.obs.trace import NULL_TRACER, Tracer
+from repro.obs import trace as obs_trace
+from repro.obs.trace import DEFAULT_TRACER, Tracer
 from repro.serving.engine import ServingEngine, ServingStats
 from repro.serving.request import Request
 
@@ -97,10 +103,52 @@ def test_tracer_thread_safe_spans():
 
 
 def test_null_tracer_is_inert():
-    assert not NULL_TRACER.enabled
-    with NULL_TRACER.span("x", a=1):
-        NULL_TRACER.instant("y")
-    assert NULL_TRACER.export()["traceEvents"] == []
+    """With no profiler session the default tracer records nothing and
+    hands out the shared no-op span."""
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    before = len(DEFAULT_TRACER.events())
+    assert not DEFAULT_TRACER.enabled
+    assert DEFAULT_TRACER.span("x", a=1) is obs_trace._NULL_SPAN
+    with DEFAULT_TRACER.span("x", a=1):
+        DEFAULT_TRACER.instant("y")
+        DEFAULT_TRACER.counter("z", n=1)
+    assert len(DEFAULT_TRACER.events()) == before
+
+
+def test_long_span_kept_when_session_starts_inside_it(tmp_path):
+    """Spans under way at a profiler session's edges: a long span opened
+    before the session and one open when it stops are kept whole, as is
+    an ordinary span open when it stops; an ordinary span opened before
+    the session is not, nor anything after it."""
+    tr = Tracer(always=False)
+    t_open = time.perf_counter_ns()
+    cycle = tr.long_span("cycle")
+    cycle.__enter__()
+    with tr.span("early"):
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("inside"):
+            pass
+        cycle.__exit__(None, None, None)
+        late, late_cycle = tr.span("late"), tr.long_span("late_cycle")
+        late.__enter__()
+        late_cycle.__enter__()
+    finally:
+        jax.profiler.stop_trace()
+    late.__exit__(None, None, None)
+    late_cycle.__exit__(None, None, None)
+    t_close = time.perf_counter_ns()
+    with tr.long_span("after"):
+        pass
+    with tr.span("after"):
+        pass
+    evs = {e[1]: e for e in tr.events()}
+    assert set(evs) == {"cycle", "inside", "late", "late_cycle"}
+    assert t_open <= evs["cycle"][2] < evs["inside"][2]
+    for name in ("late", "late_cycle"):
+        assert evs[name][2] + evs[name][3] <= t_close
+        assert evs[name][3] > 0
 
 
 # ============================================================== registry
@@ -402,3 +450,100 @@ def test_system_trace_covers_training(tmp_path):
     snap = sys_.snapshot()
     assert snap["train.cycles"] == sys_.summary()["train_cycles"]
     assert snap["train.deploy_version"] == sys_.gate.version
+
+
+# ============================================= profiler-session tracing
+# the spans this layer adds to tile the serving loop and the sync train
+# cycle (beside superstep.dispatch/unpack, prefill.*, refill, idle, ...)
+NEW_SPANS = {"serve.start", "superstep.wait", "signals.fetch",
+             "refill.first_wait", "serve.boundary", "serve.deliver",
+             "prefill.advance", "signals.flush", "train.stack",
+             "train.feed", "train.step", "train.read", "train.eval"}
+_PROF_TCFG = dict(gamma=3, batch_size=2, max_len=64, adaptive_spec=False,
+                  selective_training=False, signal_window=8,
+                  n_threshold=4, train_epochs=1, train_min_steps=6,
+                  prefill_chunk=8, seed=0)
+
+
+def _prof_requests():
+    rng = np.random.default_rng(4)
+    return [Request(prompt=list(rng.integers(1, 50, int(n))),
+                    max_new_tokens=20)
+            for n in rng.integers(9, 20, 6)]
+
+
+def _union_s(intervals):
+    return sum(e - s for s, e in devtrace.union(intervals))
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """One stream with sync training served with no profiler session,
+    and the same stream on a fresh system under one: their requests and
+    dispatch counts, the default tracer's events of the traced stream,
+    its host time span, and the profile's planes."""
+    cfg, params, dcfg, dparams = _get_model()
+    runs = {}
+    for mode in ("off", "on"):
+        sys_ = TideSystem(cfg, params, TideConfig(**_PROF_TCFG),
+                          dparams=dparams)
+        reqs = _prof_requests()
+        if mode == "off":
+            sys_.run_stream(reqs)
+        else:
+            tdir = tmp_path_factory.mktemp("profile")
+            with jax.profiler.trace(str(tdir)):
+                t_a = time.perf_counter_ns()
+                sys_.run_stream(reqs)
+                t_b = time.perf_counter_ns()
+        runs[mode] = (reqs, sys_.engine.stats.dispatches,
+                      sys_.summary()["train_cycles"])
+        sys_.close()
+    evs = [e for e in DEFAULT_TRACER.events()
+           if t_a <= e[2] and e[2] + e[3] <= t_b]
+    planes = devtrace.planes_of(devtrace.find_xplane(str(tdir)))
+    return {"runs": runs, "events": evs, "window": (t_a, t_b),
+            "planes": planes}
+
+
+def test_profiled_stream_records_new_spans(profiled):
+    assert profiled["runs"]["on"][2] >= 1, "the stream never trained"
+    names = {e[1] for e in profiled["events"]}
+    assert NEW_SPANS | {"superstep.dispatch", "superstep.unpack",
+                        "train.cycle", "prefill.chunk"} <= names
+    deliveries = [e for e in profiled["events"] if e[1] == "serve.deliver"]
+    assert all(e[5]["rids"] for e in deliveries)
+
+
+def test_profiled_spans_in_profile_host_plane(profiled):
+    host = {ev[0] for p in profiled["planes"]
+            if p["name"].startswith("/host:")
+            for ln in p["lines"] for ev in ln["events"]}
+    assert NEW_SPANS | {"superstep.dispatch", "train.cycle"} <= host
+
+
+def test_profiler_session_leaves_streams_identical(profiled):
+    off, on = profiled["runs"]["off"], profiled["runs"]["on"]
+    assert [r.generated for r in on[0]] == [r.generated for r in off[0]]
+    assert on[1] == off[1]
+    assert on[2] == off[2]
+
+
+def test_spans_tile_serving_loop_and_train_cycle(profiled):
+    """The serving thread's spans cover at least 95% of the stream's
+    wall time, and the spans inside each train cycle at least 95% of
+    the cycle."""
+    evs = [e for e in profiled["events"] if e[0] == "X"]
+    t_a, t_b = profiled["window"]
+    serving = {e[4] for e in evs if e[1] == "superstep.dispatch"}
+    assert len(serving) == 1
+    tid = serving.pop()
+    mine = [(s, s + d) for _, _, s, d, t, _ in evs if t == tid]
+    assert _union_s(mine) >= 0.95 * (t_b - t_a)
+    cycles = [e for e in evs if e[1] == "train.cycle"]
+    assert cycles
+    for _, _, c0, cd, ctid, _ in cycles:
+        inner = [(s, s + d) for _, name, s, d, t, _ in evs
+                 if t == ctid and name.startswith("train.")
+                 and name != "train.cycle" and c0 <= s and s + d <= c0 + cd]
+        assert _union_s(inner) >= 0.95 * cd

@@ -147,7 +147,7 @@ class TideConfig:
     gate_arrivals: bool = False       # respect trace arrival timestamps
     prefill_chunk: int = 0            # >0: chunked refill prefill (bound
     #                                   the long-prompt refill stall to
-    #                                   one chunk per superstep gap);
+    #                                   batch x chunk row-tokens a gap);
     #                                   applies to waves and streams alike
     # ---- paged KV cache (core/paging.py; 0 = dense per-lane caches)
     page_size: int = 0                # >0: block-table page pools with

@@ -59,6 +59,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -82,6 +85,10 @@ class _Span:
             self._ann = TraceAnnotation(self.name)
             self._ann.__enter__()
         return self
+
+    def set(self, **args):
+        """Add arguments known only once the span's work is done."""
+        self.args.update(args)
 
     def __exit__(self, *exc):
         if self._ann is not None:

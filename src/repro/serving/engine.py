@@ -56,9 +56,17 @@ Architecture (slot lifecycle):
     prompt width — the long-tail-prompt convoy effect.  Chunked, each
     refill group becomes a **chunk pipeline**: the prompt is prefilled
     into a *staging* cache pair in fixed-width chunks (a ragged first
-    chunk <= C, then exactly C), one chunk per inter-superstep gap, so
-    the longest uninterruptible prefill op is C wide no matter the
-    prompt.  The first chunk is a plain prefill; continuations extend
+    chunk <= C, then exactly C), so the longest uninterruptible prefill
+    op is C wide no matter the prompt.  Each inter-superstep gap runs a
+    chunk plan under a prefill budget of ``batch x C`` row-tokens (pad
+    rows counted): every live pipeline advances one chunk, then the
+    budget left over goes chunk by chunk to the pipeline with the most
+    prompt columns left, so a lone long prompt prefills in one gap and
+    a cohort's members finish together.  The budget is the gap a full
+    batch admitted at once takes at one chunk per pipeline, so the
+    worst gap resident lanes see does not grow; it only spends what
+    goes unused when few rows prefill.  The first chunk is a plain
+    prefill; continuations extend
     the staging caches through the decode path and feed the draft
     seeding the same chunk's (capture, next-token) pairs
     (``eagle.seed_chunk_pairs``) — bitwise-identical to the one-shot
@@ -78,8 +86,8 @@ Architecture (slot lifecycle):
     as a one-shot refill's (``EagerCommit`` trades that density for
     short-prompt TTFT); with no
     resident lane decoding (stream prologue, drained-empty supersteps)
-    chunks run back-to-back to the next commit instead of trickling
-    one per empty gap.  Mid-prefill lanes stay inert
+    chunk plans run back-to-back to the next commit instead of
+    trickling one per empty gap.  Mid-prefill lanes stay inert
     for decode masks and the reseed ring until their commit; stats count
     them separately (``prefill_lane_rounds`` — excluded from the
     occupancy denominator) and the TTFT clock starts at *admission*
@@ -90,7 +98,7 @@ Architecture (slot lifecycle):
     unchanged).  Deterministic stall metrics:
     ``stats.prefill_op_width.max`` (longest uninterruptible prefill op)
     and ``stats.prefill_gap_tokens`` / ``prefill_row_tokens``
-    (per-gap / total prefill row-tokens), gated in
+    (per-plan / total prefill row-tokens), gated in
     ``benchmarks/bench_continuous.py`` next to the wall-clock goodput.
   * **Paged KV serving** (``page_size=P`` > 0): every target/draft KV
     leaf becomes a pool of fixed ``P``-token pages behind one
@@ -468,6 +476,10 @@ class _ChunkPipeline:
     def done(self) -> bool:
         return self.pos >= self.width
 
+    def width_at(self, pos: int) -> int:
+        """Width of the chunk that starts at prompt column ``pos``."""
+        return self.first_width if pos == 0 else self.chunk
+
 
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, params, dcfg: ModelConfig,
@@ -580,6 +592,11 @@ class ServingEngine:
             raise ValueError(f"prefill_chunk {config.prefill_chunk} must "
                              "be a multiple of 8 (refill shape bucket)")
         self.prefill_chunk = config.prefill_chunk
+        # per-gap prefill budget in row-tokens: the gap a full batch
+        # admitted at once takes at one chunk per pipeline, so spending
+        # it when fewer rows prefill never lengthens the worst gap
+        # resident lanes see (``_plan_gap``)
+        self._gap_budget = self.batch * self.prefill_chunk
         # >0 switches the target + draft caches from dense per-lane
         # buffers to block-table page pools (core/paging.py): lanes
         # reserve pages at admission (the scheduler defers on pool
@@ -1453,7 +1470,7 @@ class ServingEngine:
                 # chunked prefill: no one-shot prologue — the initial
                 # batch flows through the same chunk pipelines as every
                 # later refill, so no prompt ever stalls the engine for
-                # more than one chunk per gap
+                # more than one gap's prefill budget
                 cache, dcache, carry, first = self._empty_state()
                 self._pipelines = []
                 self._spawn_pipelines(admitted)
@@ -1935,7 +1952,7 @@ class ServingEngine:
     def _chunk_args(self, pl: _ChunkPipeline):
         """Host-side slices for the pipeline's next chunk: (width,
         chunk tokens, lookahead-shifted draft-pair tokens, advance)."""
-        w = pl.first_width if pl.pos == 0 else pl.chunk
+        w = pl.width_at(pl.pos)
         a, b = pl.pos, pl.pos + w
         toks_c = pl.toks[:, a:b]
         # draft pairs are (capture i, token i+1): lookahead-shifted token
@@ -1978,13 +1995,45 @@ class ServingEngine:
                                    round_=self.stats.steps,
                                    pos=pl.pos, width=w)
 
+    def _plan_gap(self, cache, dcache) -> List[_ChunkPipeline]:
+        """This gap's chunk dispatches, in order (a pipeline appears
+        once per chunk), under the per-gap prefill budget of
+        ``batch x prefill_chunk`` row-tokens (pad rows counted, as
+        ``prefill_row_tokens`` counts them).
+
+        Every live pipeline first advances one chunk, so none starves.
+        The budget left over then goes chunk by chunk to the pipeline
+        with the most prompt columns left, so the members of a cohort
+        finish in the same gap, until the next chunk would pass the
+        budget.  When the live pipelines' rows fill the budget this is
+        one chunk per pipeline.  Spawn-time prefix resumes are seeded
+        first (they move a pipeline's start column)."""
+        live = [pl for pl in self._pipelines if not pl.ready]
+        for pl in live:
+            if pl.resume_q and pl.pos == 0:
+                self._resume_pipeline(pl, cache, dcache)
+        plan = list(live)                      # the one-chunk floor
+        spent = sum(pl.rows * pl.width_at(pl.pos) for pl in live)
+        # prompt columns each pipeline has left after the floor; every
+        # further chunk is exactly prefill_chunk wide
+        left = [pl.width - pl.pos - pl.width_at(pl.pos) for pl in live]
+        while live:
+            i = max(range(len(live)), key=left.__getitem__)
+            cost = live[i].rows * self.prefill_chunk
+            if left[i] <= 0 or spent + cost > self._gap_budget:
+                break
+            spent += cost
+            left[i] -= self.prefill_chunk
+            plan.append(live[i])
+        return plan
+
     def _advance_pipelines_ss(self, cache, dcache, state, max_new,
                               pending):
-        """Advance every in-flight pipeline by one chunk, with
-        cohort-synchronized commits.
+        """Advance the in-flight pipelines by one gap's chunk plan
+        (``_plan_gap``), with cohort-synchronized commits.
 
-        Pass 1 — chunks: each non-ready pipeline dispatches its next
-        chunk.  A pipeline that is the *only* member of its cohort runs
+        Pass 1 — chunks: each planned chunk is dispatched in plan
+        order.  A pipeline that is the *only* member of its cohort runs
         its final chunk fused with the commit (one dispatch, like a
         one-shot refill); a pipeline with cohort siblings runs its
         final chunk unfused and waits (``ready``).
@@ -2009,13 +2058,8 @@ class ServingEngine:
             else:
                 self._commit_firsts(fdev, pl.admitted)
 
-        for pl in self._pipelines:
-            if pl.ready:
-                continue
-            if pl.resume_q and pl.pos == 0:
-                self._resume_pipeline(pl, cache, dcache)
-            w, toks_c, nxt, adv_j = self._chunk_args(pl)
-            if pl.pos + w < pl.width:          # interior chunk
+        for pl in self._plan_gap(cache, dcache):
+            if pl.pos + pl.width_at(pl.pos) < pl.width:   # interior chunk
                 gap_tokens += self._advance_pipeline(pl)
                 continue
             # commit policy: eager pipelines always commit alone (fused
@@ -2030,6 +2074,7 @@ class ServingEngine:
                 gap_tokens += self._advance_pipeline(pl)
                 pl.ready = True
                 continue
+            w, toks_c, nxt, adv_j = self._chunk_args(pl)
             with self.tracer.span("prefill.chunk", rows=pl.rows,
                                   width=w, fused_commit=True):
                 if pl.pos == 0:
@@ -2080,19 +2125,18 @@ class ServingEngine:
 
     def _advance_pipelines_step(self, cache, dcache, carry, active, sids,
                                 steps):
-        """Pipeline advance for the per-step reference loop: commits
-        scatter the staging lanes into the live carry and update the
-        host lane masks in place (no telemetry pipelining here)."""
+        """Pipeline advance for the per-step reference loop, by the same
+        per-gap chunk plan as the superstep loop (``_plan_gap``):
+        commits scatter the staging lanes into the live carry and update
+        the host lane masks in place (no telemetry pipelining here)."""
         gap_tokens = 0
-        live = []
+        committed = []
         cache, dcache = self._ship_tables(cache, dcache)
-        for pl in self._pipelines:
-            if pl.resume_q and pl.pos == 0:
-                self._resume_pipeline(pl, cache, dcache)
+        for pl in self._plan_gap(cache, dcache):
             gap_tokens += self._advance_pipeline(pl)
             if not pl.done:
-                live.append(pl)
                 continue
+            committed.append(pl)
             cache, dcache, carry, fdev = self._chunk_commit_step_fn(
                 self.params, self.dparams, cache, dcache, carry,
                 pl.cache, pl.dcache, pl.logits, pl.caps_last, pl.mask,
@@ -2105,7 +2149,8 @@ class ServingEngine:
                 active[slot] = req.finish_t is None
                 sids[slot] = req.sid
                 steps[slot] = 1
-        self._pipelines = live
+        self._pipelines = [pl for pl in self._pipelines
+                           if pl not in committed]
         return cache, dcache, carry, gap_tokens
 
     def _empty_state(self):
@@ -2163,14 +2208,18 @@ class ServingEngine:
             # gaps where residents decode)
             gap = 0
             commits = 0
-            with self.tracer.span("prefill.advance"):
+            chunks0 = self.stats.prefill_chunks
+            with self.tracer.span("prefill.advance") as sp:
                 while self._pipelines and commits == 0:
                     cache, dcache, state, max_new, g, commits = \
                         self._advance_pipelines_ss(cache, dcache, state,
                                                    max_new, None)
+                    # each back-to-back plan is a gap of its own in the
+                    # stall metrics: no lane decodes between them
+                    self.stats.prefill_gap_tokens.add(g)
                     gap += g
-            if gap:
-                self.stats.prefill_gap_tokens.add(gap)
+                sp.set(chunks=self.stats.prefill_chunks - chunks0,
+                       row_tokens=gap)
         # one-superstep double buffer: superstep t+1 is dispatched before
         # t's telemetry is pulled, so the D2H sync overlaps device
         # compute; refills scheduled after draining t are enqueued behind
@@ -2237,9 +2286,11 @@ class ServingEngine:
                     cache, dcache, state, max_new, gap = \
                         self._refill_one_shot(admitted, cache, dcache,
                                               state, max_new, pending)
+                self.stats.prefill_gap_tokens.add(gap)
                 gap_tokens += gap
             elif admitted or self._pipelines:
-                with self.tracer.span("prefill.advance"):
+                chunks0 = self.stats.prefill_chunks
+                with self.tracer.span("prefill.advance") as sp:
                     if admitted:
                         # chunked: new pipelines; their first chunks
                         # dispatch in the advance below, in the refill
@@ -2248,19 +2299,22 @@ class ServingEngine:
                     cache, dcache, state, max_new, gap, commits = \
                         self._advance_pipelines_ss(cache, dcache, state,
                                                    max_new, pending)
+                    self.stats.prefill_gap_tokens.add(gap)
                     gap_tokens += gap
                     # the drained superstep was empty (no resident lane
                     # decoding): nothing to interleave with, so run the
                     # pipelines straight to the next commit instead of
-                    # trickling one idle chunk per empty dispatch
+                    # trickling one plan per empty dispatch (each plan
+                    # still a gap of its own in the stall metrics)
                     while (self._pipelines and not progressed
                            and commits == 0):
                         cache, dcache, state, max_new, gap, commits = \
                             self._advance_pipelines_ss(
                                 cache, dcache, state, max_new, pending)
+                        self.stats.prefill_gap_tokens.add(gap)
                         gap_tokens += gap
-            if gap_tokens:
-                self.stats.prefill_gap_tokens.add(gap_tokens)
+                    sp.set(chunks=self.stats.prefill_chunks - chunks0,
+                           row_tokens=gap_tokens)
             # defensive stall guard: every drained superstep must either
             # commit rounds, retire requests, admit new ones, or move a
             # chunk pipeline forward

@@ -35,6 +35,7 @@ import repro.configs as C
 from repro.core import eagle
 from repro.models import transformer as T
 from repro.serving.engine import ServingEngine
+from repro.serving.policy import ServingConfig
 from repro.serving.request import Request
 from repro.serving.scheduler import Scheduler
 from repro.serving.stats import Peak
@@ -93,8 +94,8 @@ def _requests(cfg, lens, budgets, seed=3):
 
 
 def _run_pipeline(eng, admitted):
-    """Drive one chunk pipeline to completion, as the stream loop would
-    (one advance call per gap), and return its staging state."""
+    """Drive one chunk pipeline to completion, chunk by chunk, as the
+    stream loop would, and return its staging state."""
     pl = eng._make_pipeline(admitted)
     while not pl.done:
         eng._advance_pipeline(pl)
@@ -386,3 +387,141 @@ def test_ttft_clock_starts_at_admission(model):
         assert r.admit_t is not None and r.admit_t >= r.arrival_t
         assert r.ttft is not None and r.ttft >= 0.0
         assert r.ttft <= r.latency
+
+
+# ------------------------------------------------------ per-gap chunk plan
+# a 16-lane engine with 8-wide chunks: the per-gap prefill budget is
+# 16 x 8 = 128 row-tokens
+PLAN_BATCH, PLAN_CHUNK = 16, 8
+
+
+def _record_gaps(eng, monkeypatch):
+    """Log each gap of the engine's chunk pipelines: one entry per
+    advance call (either loop), holding the pipelines live at its start
+    with their start columns, the chunks it dispatched as (pipeline,
+    start column, width), and the pipelines it committed."""
+    gaps = []
+    obs_chunk = eng._obs_chunk
+
+    def _obs(pl, w):
+        gaps[-1]["chunks"].append((pl, pl.pos - w, w))
+        obs_chunk(pl, w)
+
+    monkeypatch.setattr(eng, "_obs_chunk", _obs)
+    for name in ("_advance_pipelines_ss", "_advance_pipelines_step"):
+        def _advance(*a, _fn=getattr(eng, name), **kw):
+            before = list(eng._pipelines)
+            gap = {"live": [(pl, pl.pos) for pl in before if not pl.ready],
+                   "chunks": []}
+            gaps.append(gap)
+            out = _fn(*a, **kw)
+            gap["committed"] = [pl for pl in before
+                                if pl not in eng._pipelines]
+            return out
+        monkeypatch.setattr(eng, name, _advance)
+    return gaps
+
+
+def _plan_engine(rounds=8, commit="cohort"):
+    key = ("plan", rounds, commit)
+    eng = _ENGINES.get(key)
+    if eng is None:
+        cfg, params, dcfg, dparams = _get_model()
+        eng = _ENGINES[key] = ServingEngine(
+            cfg, params, dcfg, dparams, config=ServingConfig(
+                batch_size=PLAN_BATCH, max_len=96, gamma=3, seed=5,
+                superstep_rounds=rounds, prefill_chunk=PLAN_CHUNK,
+                commit=commit))
+    eng.reset_adaptation(eng.dparams)
+    return eng
+
+
+@pytest.mark.parametrize("rounds,widths", [
+    (8, (64,)),           # one row, 8 chunks: superstep loop
+    (0, (64,)),           # the same in the per-step loop
+    (8, (16, 40, 64)),    # a cohort of three widths, 120 row-tokens
+], ids=["one-row", "one-row-stepwise", "cohort"])
+def test_gap_plan_finishes_a_covered_admission_in_one_gap(
+        model, monkeypatch, rounds, widths):
+    """An admission whose chunks all fit the per-gap budget, made while
+    resident lanes decode, dispatches every chunk of every member in the
+    gap that admits it, and commits there: fused with the final chunk
+    when alone, with its cohort when not."""
+    cfg = model[0]
+    k = len(widths)
+    # k residents retire after their first superstep, freeing k lanes
+    # for the admission; the rest keep decoding through it
+    reqs = _requests(cfg, [8] * PLAN_BATCH + list(widths),
+                     [2] * k + [40] * (PLAN_BATCH - k) + [4] * k)
+    eng = _plan_engine(rounds)
+    gaps = _record_gaps(eng, monkeypatch)
+    eng.serve_stream(list(reqs))
+    assert all(r.finish_t is not None for r in reqs)
+    late = reqs[PLAN_BATCH:]
+    rids = {r.rid for r in late}
+    mine = [g for g in gaps
+            if any(r.rid in rids for pl, _, _ in g["chunks"]
+                   for _, r in pl.admitted)]
+    assert len(mine) == 1, "the admission's chunks spread over gaps"
+    gap = mine[0]
+    assert sum(pl.rows * w for pl, _, w in gap["chunks"]) \
+        <= PLAN_BATCH * PLAN_CHUNK
+    assert len(gap["chunks"]) == sum(-(-w // PLAN_CHUNK) for w in widths)
+    assert sorted(r.rid for pl in gap["committed"]
+                  for _, r in pl.admitted) == sorted(rids)
+    # residents decoded on across the admission: not the cold start
+    assert gap is not gaps[0]
+    assert max(r.first_token_round for r in late) \
+        < min(r.finish_round for r in reqs[k:PLAN_BATCH])
+
+
+_PLAN_RNG = np.random.default_rng(17)
+_PLAN_LENS = [int(x) for x in _PLAN_RNG.integers(8, 73, 32)]
+_PLAN_BUDGETS = [int(x) for x in _PLAN_RNG.integers(1, 14, 32)]
+
+
+@pytest.mark.parametrize("rounds,commit", [
+    (8, "cohort"), (8, "eager"), (0, "cohort")])
+def test_gap_plan_budget_and_floor(model, monkeypatch, rounds, commit):
+    """In every gap each live pipeline advances at least one chunk (the
+    floor), and chunks beyond the floor only while the gap stays within
+    ``batch x chunk`` row-tokens.  Mixed widths admitted mid-stream, so
+    some gaps do spend budget beyond the floor; every op stays one chunk
+    wide and every request gets its full budget."""
+    cfg = model[0]
+    lens, budgets = _PLAN_LENS, _PLAN_BUDGETS
+    reqs = _requests(cfg, lens, budgets)
+    eng = _plan_engine(rounds, commit)
+    gaps = _record_gaps(eng, monkeypatch)
+    eng.serve_stream(list(reqs))
+    budget = PLAN_BATCH * PLAN_CHUNK
+    extra = 0
+    for gap in gaps:
+        floor = sum(pl.rows * pl.width_at(pos) for pl, pos in gap["live"])
+        spent = sum(pl.rows * w for pl, _, w in gap["chunks"])
+        assert {id(pl) for pl, _ in gap["live"]} \
+            <= {id(pl) for pl, _, _ in gap["chunks"]}, "a pipeline starved"
+        assert spent <= max(budget, floor)
+        extra += len(gap["chunks"]) - len(gap["live"])
+    assert extra > 0, "no gap spent its budget beyond the floor"
+    assert eng.stats.prefill_op_width.max <= PLAN_CHUNK
+    assert eng.stats.prefill_row_tokens == sum(
+        pl.rows * w for g in gaps for pl, _, w in g["chunks"])
+    assert all(len(r.generated) == r.max_new_tokens for r in reqs)
+
+
+def test_gap_plan_same_in_both_loops(model, monkeypatch):
+    """The per-step and superstep loops dispatch the same chunk sequence,
+    gap by gap, for the same admission: both take the one plan."""
+    cfg = model[0]
+    lens = (72, 9, 40, 64, 17, 33, 56, 8, 72, 24)
+    seqs = {}
+    for rounds in (8, 0):
+        reqs = _requests(cfg, lens, [6] * len(lens))
+        eng = _plan_engine(rounds)
+        gaps = _record_gaps(eng, monkeypatch)
+        eng.serve_stream(list(reqs))
+        seqs[rounds] = [[(pl.order, a, w) for pl, a, w in g["chunks"]]
+                        for g in gaps if g["chunks"]]
+    assert len(seqs[8]) > 1, "the admission fit one gap; widen it"
+    assert seqs[0] == seqs[8]

@@ -388,8 +388,8 @@ def test_chunked_long_prompt_stream_invariance(pretrained):
     assert e_ss.stats.tokens_out == sum(len(r.generated) for r in r_ss)
     assert e_ss.stats.prefill_op_width.max <= chunk
     assert e_ss.stats.prefill_chunks >= len(r_ss)
-    # mid-prefill lanes were accounted separately, not as idle capacity
-    assert e_ss.stats.prefill_lane_rounds > 0
+    # the per-gap prefill budget: no gap past batch x chunk row-tokens
+    assert e_ss.stats.prefill_gap_tokens.max <= e_ss.batch * chunk
     assert e_ss.stats.lane_rounds == e_ss.stats.steps * e_ss.batch
 
     r_one = _chunk_requests(pretrained)
@@ -398,6 +398,22 @@ def test_chunked_long_prompt_stream_invariance(pretrained):
     assert [r.generated for r in r_one] == [r.generated for r in r_ss], \
         "chunked stream diverged from one-shot refill"
     assert e_one.stats.prefill_op_width.max >= 48   # the long-tail stall
+
+    # one chunk per pipeline per gap (no budget beyond the floor): the
+    # same streams, and the budgeted engine's prefill ran in fewer gaps
+    r_pc = _chunk_requests(pretrained)
+    e_pc = _chunk_engine(pretrained, 8, chunk=chunk)
+    e_pc._gap_budget = 0
+    e_pc.serve_stream(list(r_pc))
+    assert [r.generated for r in r_pc] == [r.generated for r in r_ss], \
+        "budgeted chunk plan diverged from one chunk per gap"
+    assert e_ss.stats.prefill_gap_tokens.n < e_pc.stats.prefill_gap_tokens.n
+    # mid-prefill lanes were accounted separately, not as idle capacity:
+    # at one chunk per gap they sit inert across supersteps, and the
+    # budget commits them sooner
+    assert e_pc.stats.prefill_lane_rounds > 0
+    assert e_pc.stats.lane_rounds == e_pc.stats.steps * e_pc.batch
+    assert e_ss.stats.prefill_lane_rounds < e_pc.stats.prefill_lane_rounds
 
     r_st = _chunk_requests(pretrained)
     e_st = _chunk_engine(pretrained, 0, chunk=chunk)

@@ -357,6 +357,12 @@ def test_engine_recorder_covers_chunked_prefill():
         assert "prefill_chunk" in kinds
     names = {e[1] for e in eng.tracer.events()}
     assert "prefill.chunk" in names
+    # every chunk is dispatched inside a prefill.advance span, which
+    # counts the gap's chunks and row-tokens
+    adv = [e[5] for e in eng.tracer.events() if e[1] == "prefill.advance"]
+    assert sum(a["chunks"] for a in adv) == eng.stats.prefill_chunks
+    assert sum(a["row_tokens"] for a in adv) \
+        == eng.stats.prefill_row_tokens
 
 
 # ======================================================== system parity

@@ -19,6 +19,8 @@ from typing import Dict, List, Optional
 import jax
 import numpy as np
 
+from repro.obs.trace import DEFAULT_TRACER
+
 
 @dataclasses.dataclass
 class SignalBatch:
@@ -162,9 +164,10 @@ class SignalExtractor:
     """
 
     def __init__(self, store: SignalStore, window: int = 64,
-                 feat_dim: int = 0):
+                 feat_dim: int = 0, tracer=DEFAULT_TRACER):
         self.store = store
         self.window = window
+        self.tracer = tracer
         self._pending = None     # device arrays from the previous step
         self._acc: Dict[int, List] = {}   # rid -> [(feat, tok), ...]
         self.enabled = True
@@ -228,17 +231,24 @@ class SignalExtractor:
                 self._emit(rid)
 
     def _emit(self, rid, force: bool = False):
+        """Hand ``rid``'s full windows (and with ``force`` its residual
+        one) to the store, inside a ``signals.ship`` span: the windows
+        stacked and, on a channel to a trainer device, their copies
+        enqueued."""
         acc = self._acc.get(rid, [])
-        while len(acc) >= self.window:
-            chunk, acc = acc[:self.window], acc[self.window:]
-            self.store.add(SignalBatch(
-                feats=np.stack([c[0] for c in chunk]),
-                tokens=np.array([c[1] for c in chunk], np.int32)))
-        if force and len(acc) >= 8:   # short residual windows still usable
-            self.store.add(SignalBatch(
-                feats=np.stack([c[0] for c in acc]),
-                tokens=np.array([c[1] for c in acc], np.int32)))
-            acc = []
+        # short residual windows still usable
+        if len(acc) >= self.window or (force and len(acc) >= 8):
+            with self.tracer.span("signals.ship"):
+                while len(acc) >= self.window:
+                    chunk, acc = acc[:self.window], acc[self.window:]
+                    self.store.add(SignalBatch(
+                        feats=np.stack([c[0] for c in chunk]),
+                        tokens=np.array([c[1] for c in chunk], np.int32)))
+                if force and len(acc) >= 8:
+                    self.store.add(SignalBatch(
+                        feats=np.stack([c[0] for c in acc]),
+                        tokens=np.array([c[1] for c in acc], np.int32)))
+                    acc = []
         self._acc[rid] = acc
         if force:
             self._acc.pop(rid, None)

@@ -233,7 +233,11 @@ class TideSystem:
         if dparams is None:
             dparams = eagle.draft_init(self.dcfg,
                                        jax.random.key(tide_cfg.seed + 7))
+        # the initial draft as the deploy gate holds it (on the trainer's
+        # device, if it has one), and the device the engine's copy of it
+        # is made again on at a reset (None: the same arrays)
         self._dparams0 = dparams
+        self._serve_device = None
         # one shared instrument set for every component (see module
         # docstring, "Observability"); tracer/recorder default to the
         # null singletons when TideConfig.obs is unset
@@ -283,13 +287,21 @@ class TideSystem:
             self.channel = SignalChannel(
                 capacity=max(tide_cfg.channel_capacity,
                              tide_cfg.n_threshold),
-                device=train_device)
+                device=train_device, tracer=self.tracer)
             # the trainer reads only the frozen embeddings: keep a copy
-            # on its own device, next to the drafts it trains
-            embed = (params["embed"] if train_device is None
-                     else jax.device_put(params["embed"], train_device))
-            self.trainer = DraftTrainer(cfg, self.dcfg, embed)
-            self.gate = DraftDeployGate(dparams)
+            # on its own device, next to the drafts it trains; the gate
+            # holds the trainer's drafts there too, and the engine its
+            # own copy on the serving device, which no reference here
+            # pins: the serving chip holds at most the draft it serves
+            # and the one a deploy publishes
+            embed = params["embed"]
+            if train_device is not None:
+                embed, self._dparams0 = jax.device_put(
+                    (embed, dparams), train_device)
+                self._serve_device = serve_device
+            self.trainer = DraftTrainer(cfg, self.dcfg, embed,
+                                        device=train_device)
+            self.gate = DraftDeployGate(self._dparams0)
             self.service = TrainingService(
                 self.trainer, self.gate, self.channel,
                 controller=self.controller,
@@ -298,14 +310,14 @@ class TideSystem:
                 signal_window=tide_cfg.signal_window,
                 train_epochs=tide_cfg.train_epochs,
                 train_min_steps=tide_cfg.train_min_steps,
-                seed=tide_cfg.seed,
-                device=train_device, publish_device=serve_device,
+                seed=tide_cfg.seed, publish_device=serve_device,
                 trainer_threads=tide_cfg.trainer_threads,
                 engine_steps_fn=lambda: self.engine.stats.steps,
                 tracer=self.tracer, registry=self.metrics)
         self.store = self.channel     # back-compat alias (shared storage)
         self.extractor = SignalExtractor(self.channel,
-                                         window=tide_cfg.signal_window)
+                                         window=tide_cfg.signal_window,
+                                         tracer=self.tracer)
         self.events = self.service.events
         # the engine consumes one unified ServingConfig + the composed
         # ServingPolicy it names (re-seed only makes sense with the
@@ -406,7 +418,9 @@ class TideSystem:
             self.controller.collection_enabled = True   # as in __init__
             self.gate.reset(self._dparams0)
             self.service.reset()
-            self.engine.reset_adaptation(self._dparams0)
+            self.engine.reset_adaptation(
+                self._dparams0 if self._serve_device is None
+                else jax.device_put(self._dparams0, self._serve_device))
 
     # ------------------------------------------------------------- stats
     def export_trace(self, path: Optional[str] = None) -> Dict:

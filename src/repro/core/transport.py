@@ -30,6 +30,7 @@ import threading
 from typing import Optional
 
 from repro.core.signals import SignalBatch, SignalStore
+from repro.obs.trace import DEFAULT_TRACER
 
 
 def pick_training_device(s: float = 1.2):
@@ -59,13 +60,17 @@ class SignalChannel(SignalStore):
     bound with drop-oldest semantics + drop accounting (backpressure
     stats), a condition variable so a consumer can block for samples
     (``wait``), optional producer-side ``device_put`` onto the trainer's
-    device, and ``close`` to wake blocked consumers at shutdown."""
+    device, and ``close`` to wake blocked consumers at shutdown.  Each
+    ``add`` samples two cumulative counters on ``tracer``:
+    ``signals.captured`` (windows offered) and ``signals.dropped``
+    (windows the bound dropped)."""
 
     def __init__(self, capacity: int = 512, device=None,
-                 spill_dir: Optional[str] = None):
+                 spill_dir: Optional[str] = None, tracer=DEFAULT_TRACER):
         super().__init__(spill_dir=spill_dir, max_samples=capacity)
         self.capacity = capacity
         self.device = device
+        self.tracer = tracer
         self.dropped = 0
         self.rejected_after_close = 0
         self.closed = False
@@ -106,7 +111,11 @@ class SignalChannel(SignalStore):
             while len(self._buf) > self.capacity:
                 self._buf.pop(0)
                 self.dropped += 1
+            added, dropped = self.total_added, self.dropped
             self._cond.notify_all()
+        # cumulative counts, for readers of a traced window
+        self.tracer.counter("signals.captured", value=added)
+        self.tracer.counter("signals.dropped", value=dropped)
 
     # ------------------------------------------------------------- consume
     def drain(self, n=None):

@@ -20,7 +20,6 @@ the thread disabled, it reproduces the legacy synchronous
 """
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 from typing import Any, Callable, Dict, List, NamedTuple, Optional
@@ -56,8 +55,7 @@ class TrainingService:
                  selective: bool = True,
                  n_threshold: int = 2048, signal_window: int = 24,
                  train_epochs: int = 2, train_min_steps: int = 80,
-                 seed: int = 0,
-                 device=None, publish_device=None,
+                 seed: int = 0, publish_device=None,
                  trainer_threads: int = 0,
                  engine_steps_fn: Optional[Callable[[], int]] = None,
                  poll_s: float = 0.05,
@@ -82,7 +80,6 @@ class TrainingService:
         self.train_epochs = train_epochs
         self.train_min_steps = train_min_steps
         self.seed = seed
-        self.device = device
         self.publish_device = publish_device
         # trainer-thread contention knob (ServingConfig.trainer_threads):
         # on small single-device hosts the trainer's jitted steps share
@@ -165,18 +162,14 @@ class TrainingService:
                 baseline = self.baseline_fn()
             else:
                 baseline = 0.0
+            # the gate's draft lives where the trainer trains, which
+            # places its own arrays: the cycle runs under no default
+            # device, so it calls the programs set-up compiled
             dparams, _ = self.gate.current()
-            ctx = contextlib.nullcontext()
-            if self.device is not None:
-                # the gate's draft may still be the serving device's
-                # copy; one program cannot take arguments on two devices
-                import jax
-                dparams = jax.device_put(dparams, self.device)
-                ctx = jax.default_device(self.device)
             # a cycle under way when a profiler session starts is kept
             # whole: readers clip it to their window
-            with ctx, self.tracer.long_span("train.cycle",
-                                            batches=len(batches)):
+            with self.tracer.long_span("train.cycle",
+                                       batches=len(batches)):
                 result = self.trainer.train_cycle(
                     dparams, batches, epochs=self.train_epochs,
                     min_steps=self.train_min_steps, seed=self.seed,

@@ -41,10 +41,11 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-# F = 3 * d_model: StarCoder2-7B (4608) in bf16, tide-tiny (128) in f32;
-# T = gamma + 1
+# F = 3 * d_model: StarCoder2-7B (4608) and -15B (6144) in bf16,
+# tide-tiny (128) in f32; T = gamma + 1
 @pytest.mark.parametrize("f,dtype", [(13824, jnp.bfloat16),
-                                     (384, jnp.float32)])
+                                     (384, jnp.float32),
+                                     (18432, jnp.bfloat16)])
 @pytest.mark.parametrize("t", [4, 8])
 @pytest.mark.parametrize("b", [1, 8])
 def test_extract_pack_compiles_for_v5e(one_chip, b, t, f, dtype):
